@@ -32,6 +32,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     failures = []
     for name in MODULES:

@@ -808,7 +808,10 @@ def fit(arch: Union[str, ArchConfig],
         params = model.init(jax.random.PRNGKey(seed))
         state = {"params": params, "opt_state": opt_init(params)}
         if jit:
-            step_fn = jax.jit(step_fn)
+            # donated state: each step's outputs reuse its inputs' buffers
+            # (a full-width model's params + AdamW state would not fit twice
+            # on one chip); the Trainer only ever reads the newest state
+            step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
     else:
         if state is None:
             raise TypeError("fit(train_step=...) also needs state=...")

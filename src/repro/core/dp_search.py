@@ -84,7 +84,10 @@ class SearchConfig:
     n_microbatches: int = 128
     monotone_clusters: bool = False   # True = paper's exact Eq. 14/15 ordering
     require_all_devices: bool = False
-    n_workers: int = 0                # 0 -> serial
+    n_workers: int = 0                # 0 -> serial; > 0 forks workers,
+                                      # which must never happen in a process
+                                      # that holds a TPU (the children would
+                                      # inherit the runtime's device handles)
     tmax_round_digits: int = 4        # dedupe candidates to this many sig digits
     max_candidates: int = 512
     intra_overlap: float = 0.0        # fraction of intra-op collective time

@@ -431,7 +431,12 @@ def _simulate_graph(t_f, t_b, c_links, B, warmup_counts, cb, in_f, in_b, *,
     stage_idle = [makespan - stage_compute[i] - stage_comm_blocking[i]
                   for i in range(S)]
 
-    comm_total = sum(d for (k, _, _), d in dur.items() if k in ("CF", "CB"))
+    # a plain left-to-right float sum, as the fast path accumulates it
+    # (Python 3.12's sum() compensates, which can differ in the last bit)
+    comm_total = 0.0
+    for (k, _, _), d in dur.items():
+        if k in ("CF", "CB"):
+            comm_total += d
     # exposed comm: compute ops delayed specifically by their comm dependency
     comm_exposed = 0.0
     for v, ps in deps.items():

@@ -40,7 +40,7 @@ def shape_bucket(shape: Iterable[int]) -> Tuple[int, ...]:
 class KernelMeasurement:
     """One measured cell of the latency table."""
 
-    device: str                      # device fingerprint, e.g. "tpu:TPU v5e"
+    device: str                      # device fingerprint, e.g. "tpu:TPU v5 lite"
     op: str                          # op name in the harness registry
     shape: Tuple[int, ...]           # the shape actually measured
     median_s: float                  # median wall-clock seconds per call

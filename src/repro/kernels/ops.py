@@ -8,12 +8,12 @@ non-TPU backends, compiled Mosaic on TPU).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import compat
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
@@ -23,16 +23,6 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
-
-
-def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
-    n = x.shape[axis]
-    pad = (-n) % mult
-    if pad == 0:
-        return x
-    cfg = [(0, 0)] * x.ndim
-    cfg[axis] = (0, pad)
-    return jnp.pad(x, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +59,6 @@ def tuned_blocks(op: str, shape):
     hit = entries.get(shape)
     if hit is not None:
         return hit
-    import math
     same_rank = [s for s in entries if len(s) == len(shape)]
     if not same_rank:
         return None
@@ -93,77 +82,33 @@ def _flash(q, k, v, causal, window, block_q, block_k, interpret):
     return out
 
 
+def _kernel_layout(x: jnp.ndarray) -> jnp.ndarray:
+    """Model layout (B, T, H, D) <-> kernel layout (B, H, T, D)."""
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
 def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret):
-    # model layout (B, T, H, D) -> kernel layout (B, H, T, D)
-    B, T, H, D = q.shape
-    S = k.shape[1]
-    qt = _pad_to(jnp.transpose(q, (0, 2, 1, 3)), 2, block_q)
-    kt = _pad_to(jnp.transpose(k, (0, 2, 1, 3)), 2, block_k)
-    vt = _pad_to(jnp.transpose(v, (0, 2, 1, 3)), 2, block_k)
-    # real (unpadded) lengths drive the kernel masks
-    ot, lse = _pallas_fwd(qt, kt, vt, causal, window, T, S, block_q, block_k,
-                          interpret)
-    out = jnp.transpose(ot[:, :, :T], (0, 2, 1, 3))
-    return out, (q, k, v, ot, lse)
-
-
-def _pallas_fwd(qt, kt, vt, causal, window, q_len, kv_len, block_q, block_k,
-                interpret):
-    kernel = functools.partial(
-        _fa._fwd_kernel, scale=qt.shape[-1] ** -0.5, causal=causal,
-        window=window, q_len=q_len, kv_len=kv_len, block_q=block_q,
-        block_k=block_k, nk=kt.shape[2] // block_k)
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    B, H, Tq, D = qt.shape
-    rep = H // kt.shape[1]
-    return pl.pallas_call(
-        kernel,
-        grid=(B, H, Tq // block_q, kt.shape[2] // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(qt, kt, vt)
+    ot, lse = _fa.flash_attention_fwd(
+        _kernel_layout(q), _kernel_layout(k), _kernel_layout(v),
+        causal=causal, window=window, block_q=block_q, block_k=block_k,
+        interpret=interpret)
+    return _kernel_layout(ot), (q, k, v, ot, lse)
 
 
 def _flash_bwd(causal, window, block_q, block_k, interpret, res, g):
     q, k, v, ot, lse = res
     B, T, H, D = q.shape
     S, KV = k.shape[1], k.shape[2]
-    rep = H // KV
-    qt = _pad_to(jnp.transpose(q, (0, 2, 1, 3)), 2, block_q)
-    kt = _pad_to(jnp.transpose(k, (0, 2, 1, 3)), 2, block_k)
-    vt = _pad_to(jnp.transpose(v, (0, 2, 1, 3)), 2, block_k)
-    dot = _pad_to(jnp.transpose(g, (0, 2, 1, 3)), 2, block_q)
     dq, dk, dv = _fa.flash_attention_bwd(
-        qt, kt, vt, ot, lse, dot, causal=causal, window=window,
+        _kernel_layout(q), _kernel_layout(k), _kernel_layout(v), ot, lse,
+        _kernel_layout(g), causal=causal, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret)
-    dq = jnp.transpose(dq[:, :, :T], (0, 2, 1, 3))
     # reduce expanded heads back to KV groups
-    dk = dk[:, :, :S].reshape(B, KV, rep, S, D).sum(axis=2)
-    dv = dv[:, :, :S].reshape(B, KV, rep, S, D).sum(axis=2)
-    dk = jnp.transpose(dk, (0, 2, 1, 3)).astype(k.dtype)
-    dv = jnp.transpose(dv, (0, 2, 1, 3)).astype(v.dtype)
-    return dq.astype(q.dtype), dk, dv
+    dk = dk.reshape(B, KV, H // KV, S, D).sum(axis=2)
+    dv = dv.reshape(B, KV, H // KV, S, D).sum(axis=2)
+    return (_kernel_layout(dq).astype(q.dtype),
+            _kernel_layout(dk).astype(k.dtype),
+            _kernel_layout(dv).astype(v.dtype))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -223,22 +168,17 @@ def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: Optional[int] = None,
             interpret: Optional[bool] = None):
     """x: (..., D) any leading dims; w: (D,).
 
-    ``block_rows`` of None resolves through the tuned-block registry; either
-    way the block is halved until it divides the row count (the kernel
-    requires exact tiling over rows)."""
+    ``block_rows`` of None resolves through the tuned-block registry.  Either
+    way the block is rounded down to a multiple of 8 (Mosaic tiles rows in
+    sublanes of 8), capped at the row count rounded up to 8, and the rows are
+    zero-padded to a whole number of blocks."""
     interpret = _auto_interpret(interpret)
     shape = x.shape
-    rows = 1
-    for s in shape[:-1]:
-        rows *= s
-    x2 = x.reshape(rows, shape[-1])
+    rows = math.prod(shape[:-1])
     if block_rows is None:
         tuned = tuned_blocks("rmsnorm", (rows, shape[-1]))
-        block = tuned[0] if tuned else 128
-    else:
-        block = block_rows
-    block = max(1, min(block, rows))
-    while rows % block and block > 1:
-        block //= 2
+        block_rows = tuned[0] if tuned else 128
+    block = min(max(8, block_rows - block_rows % 8), rows + (-rows) % 8)
+    x2 = jnp.pad(x.reshape(rows, shape[-1]), ((0, (-rows) % block), (0, 0)))
     out = _rn.rmsnorm(x2, w, eps=eps, block_rows=block, interpret=interpret)
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)

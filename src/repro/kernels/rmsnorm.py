@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                  # (rows, D)
@@ -38,7 +36,7 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

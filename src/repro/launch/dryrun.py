@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape) on
 the production meshes, record memory/cost/collective analyses.
 
-The two lines above MUST run before any other import — jax locks the device
-count at first initialization.  Smoke tests and benches see 1 device; only
-the dry-run sees 512 placeholders.
+A CPU-placeholder tool: 512 host-platform devices stand in for the
+production meshes.  ``main`` pins this process and every per-cell child to
+the CPU platform before JAX initializes a backend, so the dry-run never
+contends for an accelerator; smoke tests and benches see 1 device.
 
 Usage:
   python -m repro.launch.dryrun --arch minitron-8b --shape train_4k --mesh single
@@ -18,6 +16,7 @@ Each cell writes results/dryrun/<arch>_<shape>_<mesh>.json with:
 """
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
@@ -37,7 +36,9 @@ from repro.models.prefill import prefill
 from repro.parallel import sharding as shd
 from repro.serve.step import make_prefill_step, make_serve_step
 from repro.train.optimizer import OptimizerConfig, OptState, make_optimizer
-from repro.train.step import make_pipeline_train_step, make_train_step
+from repro.train.step import (
+    make_pipeline_train_step, make_train_step, pipeline_state_shardings,
+)
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -105,15 +106,14 @@ def build_case(arch: str, shape_name: str, multi_pod: bool,
             # the paper's design: pipeline over the slow pod axis.
             # act_dtype=f32 works around an XLA *CPU* compiler abort
             # (AllReducePromotion aborts cloning a bf16 all-reduce produced
-            # by the pipeline backward); on TPU the target act dtype is bf16
-            # — pipeline activation-memory numbers here are 2x the target.
+            # by the pipeline backward); the TPU compiler accepts bf16, the
+            # target act dtype — pipeline activation-memory numbers here are
+            # 2x the target.
             train_step, staging, opt_init, sh = make_pipeline_train_step(
                 cfg, opt_cfg, mesh=mesh, n_stages=2,
                 n_microbatches=n_microbatches, abstract=True,
                 act_dtype=jnp.float32)
             ptree = {"staged": staging.staged, "shared": staging.shared}
-            opt_specs = {"staged": sh["staged_specs"],
-                         "shared": sh["shared_specs"]}
             opt_struct = jax.eval_shape(opt_init, ptree)
             batch_sh = jax.tree.map(
                 lambda x: NamedSharding(mesh, P("data", *([None] * (len(x.shape) - 1)))),
@@ -121,20 +121,8 @@ def build_case(arch: str, shape_name: str, multi_pod: bool,
             # donate params/opt (in-place update semantics)
             args = (staging.staged, staging.shared, staging.consts,
                     opt_struct, batch_structs)
-            staged_sh = shd.fitted_shardings(mesh, sh["staged_specs"],
-                                             staging.staged)
-            shared_sh = shd.fitted_shardings(mesh, sh["shared_specs"],
-                                             staging.shared)
-            opt_sh = OptState(
-                NamedSharding(mesh, P()),
-                {"staged": shd.fitted_shardings(mesh, sh["staged_specs"],
-                                                opt_struct.mu["staged"]),
-                 "shared": shd.fitted_shardings(mesh, sh["shared_specs"],
-                                                opt_struct.mu["shared"])},
-                {"staged": shd.fitted_shardings(mesh, sh["staged_specs"],
-                                                opt_struct.nu["staged"]),
-                 "shared": shd.fitted_shardings(mesh, sh["shared_specs"],
-                                                opt_struct.nu["shared"])})
+            staged_sh, shared_sh, opt_sh = pipeline_state_shardings(
+                mesh, sh, staging, opt_struct)
             in_sh = (staged_sh, shared_sh, sh["consts"], opt_sh, batch_sh)
             return train_step, args, in_sh, mesh, (0, 1, 3)
         # single-pod (or multi-pod DP for sub-1B audio): DP/FSDP + TP
@@ -260,7 +248,6 @@ def _combine(base: Dict[str, Any], per_mb: Dict[str, Any], n_units: float
 def run_cell(arch: str, shape_name: str, mesh_kind: str,
              out_dir: str = "results/dryrun",
              skip_analysis: bool = False) -> Dict[str, Any]:
-    from repro import compat
     from repro.models import common as mcommon
     multi_pod = mesh_kind == "multi"
     cfg = get_config(arch)
@@ -271,7 +258,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     try:
         # --- pass 1: production (scanned) — the compile proof + memory ------
         fn, args, in_sh, mesh, donate = build_case(arch, shape_name, multi_pod)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh,
                               donate_argnums=donate).lower(*args)
             rec["lower_s"] = round(time.time() - t0, 2)
@@ -338,12 +325,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
 
 def _cell_analysis(arch, shape_name, multi_pod, n_mb, global_batch):
-    from repro import compat
-    from repro.models import common as mcommon
     fn, args, in_sh, mesh, donate = build_case(
         arch, shape_name, multi_pod, n_microbatches=n_mb,
         global_batch_override=global_batch)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh,
                            donate_argnums=donate).lower(*args).compile()
     return _analyze(compiled)
@@ -359,7 +344,17 @@ def all_cells(mesh_kinds=("single", "multi")) -> List[Tuple[str, str, str]]:
     return cells
 
 
+def use_cpu_placeholders() -> None:
+    """Pin this process, and the children that inherit its environment, to
+    512 CPU placeholder devices.  Must run before JAX initializes a backend
+    (the device count and the platform are fixed then)."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main() -> None:
+    use_cpu_placeholders()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -44,21 +44,13 @@ def activation_sharding(rules: Dict[str, Optional[object]]):
         _CTX.val = prev
 
 
-# jax-version shims live in repro.compat; re-exported here because model and
-# launch code historically imported them from this module
-from repro.compat import (  # noqa: F401  (re-export)
-    ambient_mesh as _ambient_mesh, pcast_varying, set_mesh, shard_map,
-)
-
-
 def shard_act(x: jnp.ndarray, names: Sequence[Optional[str]]) -> jnp.ndarray:
     """Apply a with_sharding_constraint from logical axis names (no-op outside
     an :func:`activation_sharding` context)."""
     rules = getattr(_CTX, "val", None)
     if rules is None:
         return x
-    mesh = _ambient_mesh()
-    if mesh is None or mesh.empty:
+    if jax.sharding.get_abstract_mesh().empty:
         return x  # no ambient mesh (single-device tests): no-op
     spec = P(*[rules.get(n) if n is not None else None for n in names])
     return jax.lax.with_sharding_constraint(x, spec)

@@ -40,7 +40,6 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
 
     ``spec`` is a family staging (see ``parallel/staging.py``) providing
     make_io / stage_fn / head_loss / zero_carry."""
-    from repro import compat
     S = spec.n_stages
     n_mb = n_microbatches
 
@@ -50,7 +49,7 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
         sidx = jax.lax.axis_index(stage_axis)
         is_last = (sidx == S - 1).astype(jnp.float32)
         carry0 = jax.tree.map(
-            lambda x: compat.pcast_varying(x, stage_axis),
+            lambda x: jax.lax.pcast(x, (stage_axis,), to="varying"),
             spec.zero_carry(io))
         perm = [(i, i + 1) for i in range(S - 1)]
 
@@ -73,7 +72,7 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
                                          unroll=scan_unroll())
         return (jnp.sum(ce)[None], jnp.sum(tok)[None], jnp.sum(aux)[None])
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(stage_axis), P(stage_axis), P(), P()),
         out_specs=(P(stage_axis), P(stage_axis), P(stage_axis)),

@@ -22,7 +22,7 @@ from repro.models.common import activation_sharding
 from repro.parallel import sharding as shd
 from repro.parallel.pipeline import pipeline_loss_fn
 from repro.parallel.staging import build_staging
-from repro.train.optimizer import OptimizerConfig, make_optimizer
+from repro.train.optimizer import OptimizerConfig, OptState, make_optimizer
 
 
 def batch_pspecs(batch_tree, batch_axes=("data",)) -> Any:
@@ -165,3 +165,24 @@ def pipeline_shardings(staging, mesh) -> Dict[str, Any]:
         "shared_specs": shared_specs,
         "consts_specs": consts_specs,
     }
+
+
+def pipeline_state_shardings(mesh, shardings: Dict[str, Any], staging,
+                             opt_struct) -> Tuple[Any, Any, OptState]:
+    """Placement of the pipeline step's trainable state, every leaf's spec
+    fitted to its shape: (staged, shared, optimizer-state) NamedSharding
+    trees.  ``shardings`` is :func:`pipeline_shardings`' dict; ``opt_struct``
+    the optimizer state (or its ``eval_shape``) over ``{"staged", "shared"}``.
+
+    A loop that feeds each step's state to the next gives these as the
+    step's ``out_shardings`` too: left free, XLA may return the state placed
+    otherwise (it moved the norm weights onto ``model`` on a v5e 2x2), and
+    the compiled step then refuses it."""
+    def fitted(key, tree):
+        return shd.fitted_shardings(mesh, shardings[key + "_specs"], tree)
+
+    parts = ("staged", "shared")
+    return (fitted("staged", staging.staged), fitted("shared", staging.shared),
+            OptState(NamedSharding(mesh, P()),
+                     {k: fitted(k, opt_struct.mu[k]) for k in parts},
+                     {k: fitted(k, opt_struct.nu[k]) for k in parts}))

@@ -47,9 +47,12 @@ class Trainer:
                  clock: Callable[[], float] = time.perf_counter):
         """``state``: dict of pytrees passed through train_step in order;
         train_step(*state_values, batch) -> (*new_state_values, metrics).
-        ``on_step_time(step, dt)`` receives every measured step wall time
-        (telemetry feed for the elastic controller); ``on_straggler(step, dt,
-        ewma)`` fires only on sustained skew."""
+        ``train_step`` may donate its state arguments (``api.fit`` does):
+        each state is passed once, and only the state a step returned is
+        read afterwards — by the next step, ``checkpoint`` and the SIGTERM
+        path alike.  ``on_step_time(step, dt)`` receives every measured step
+        wall time (telemetry feed for the elastic controller);
+        ``on_straggler(step, dt, ewma)`` fires only on sustained skew."""
         self.cfg = cfg
         self.data_cfg = data_cfg
         self.train_step = train_step

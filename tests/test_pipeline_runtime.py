@@ -9,21 +9,9 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
-# The pipeline body is *partial-manual* (manual over pod, auto over
-# data/model) and reads axis_index inside it.  jax 0.4.x's SPMD partitioner
-# rejects the resulting PartitionId op ("meaning is ambiguous"); the program
-# is only expressible on jax versions with the first-class jax.shard_map API.
-pytestmark = [
-    pytest.mark.slow,   # several minutes per arch — tier-1 fast job skips
-    pytest.mark.skipif(
-        not hasattr(jax, "shard_map"),
-        reason="partial-auto shard_map pipeline needs jax.shard_map (new "
-               "jax); 0.4.x SPMD partitioning rejects PartitionId in "
-               "partial-manual bodies"),
-]
+pytestmark = pytest.mark.slow   # several minutes per arch — tier-1 fast job skips
 
 SCRIPT = r"""
 import os
@@ -31,12 +19,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, sys
 import jax, jax.numpy as jnp
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.parallel.staging import build_staging
 from repro.parallel.pipeline import pipeline_loss_fn
 
 arch = sys.argv[1]
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
 cfg = get_config(arch).reduced()
 model = build_model(cfg, param_dtype=jnp.float32)
@@ -53,8 +42,7 @@ ref_gn = float(jnp.sqrt(sum(jnp.sum(x**2) for x in jax.tree.leaves(ref_g))))
 
 st = build_staging(cfg, 2, params, act_dtype=jnp.float32)
 loss_fn = pipeline_loss_fn(st, mesh, n_microbatches=4)
-from repro.compat import set_mesh
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     loss, _ = jax.jit(loss_fn)(st.staged, st.shared, st.consts, batch)
     g = jax.jit(jax.grad(lambda s, sh: loss_fn(s, sh, st.consts, batch)[0],
                          argnums=(0, 1)))(st.staged, st.shared)
@@ -68,7 +56,7 @@ print(json.dumps({"ref": float(ref_loss), "pipe": float(loss),
 def _run(arch):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU equivalence test on 8 host devices
     out = subprocess.run([sys.executable, "-c", SCRIPT, arch],
                          capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
