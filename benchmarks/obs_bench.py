@@ -39,6 +39,7 @@ from benchmarks.common import emit_csv                        # noqa: E402
 
 from repro import api                                         # noqa: E402
 from repro.core.cluster import paper_case_study_cluster       # noqa: E402
+from repro.core.pipesim import plain_sum                      # noqa: E402
 from repro.core.planner import PlannerConfig                  # noqa: E402
 from repro.obs import DriftLedger, trace_from_sim, trace_to_chrome  # noqa: E402
 
@@ -73,10 +74,10 @@ def trace_export_case(exe, rounds: int) -> Dict:
     tr = trace_from_sim(res, name=ARCH)
     compute = [s for s in tr.spans if s.cat == "compute"]
     exact = all(
-        sum(s.dur for s in compute if s.args["stage"] == i) == expected
+        plain_sum(s.dur for s in compute if s.args["stage"] == i) == expected
         for i, expected in enumerate(res.stage_compute))
-    comm = sum(s.dur for s in tr.spans
-               if s.cat == "comm" and s.args.get("kind") in ("CF", "CB"))
+    comm = plain_sum(s.dur for s in tr.spans
+                     if s.cat == "comm" and s.args.get("kind") in ("CF", "CB"))
     return {
         "rounds": rounds,
         "n_spans": len(tr.spans),

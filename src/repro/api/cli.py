@@ -665,9 +665,3 @@ def main(argv: Optional[List[str]] = None) -> int:
             "train": cmd_train, "replay": cmd_replay,
             "migrate": cmd_migrate, "kbench": cmd_kbench,
             "chaos": cmd_chaos, "trace": cmd_trace}[args.cmd](args)
-
-
-if __name__ == "__main__":
-    from repro.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    raise SystemExit(main())

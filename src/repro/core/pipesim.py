@@ -66,6 +66,17 @@ class SimResult:
         return tokens_per_microbatch * n_microbatches / self.makespan
 
 
+def plain_sum(xs) -> float:
+    """Left-to-right float accumulation: how every engine totals its
+    breakdown, and how a reader re-adds it to get the same bits (the
+    built-in ``sum`` compensates since Python 3.12, which can differ in the
+    last bit)."""
+    acc = 0.0
+    for x in xs:
+        acc += x
+    return acc
+
+
 def _stage_order(i: int, S: int, B: int, N_i: int) -> List[Tuple[str, int]]:
     """Issue order of compute ops on stage i: warm-up forwards, 1F1B steady
     alternation, cool-down backwards."""
@@ -221,7 +232,6 @@ def _fast_result(t_f, t_b, c_links, B, warmup_counts, cb, in_f, in_b
         for _ in range(n_w):
             acc += tbi
         stage_compute[i] = acc
-    comm_total = 0.0
     for i in range(S - 1):
         row_cf, row_cb = cf_start[i], cb_start[i]
         start.update({("CF", j, i): row_cf[j] for j in range(B)})
@@ -229,9 +239,9 @@ def _fast_result(t_f, t_b, c_links, B, warmup_counts, cb, in_f, in_b
         ci, cbi = c_links[i], cb[i]
         dur.update({("CF", j, i): ci for j in range(B)})
         dur.update({("CB", j, i): cbi for j in range(B)})
-        for _ in range(B):
-            comm_total += ci
-            comm_total += cbi
+    # in the graph engine's node order: per link, CF then CB per microbatch
+    comm_total = plain_sum(d for i in range(S - 1) for _ in range(B)
+                           for d in (c_links[i], cb[i]))
     makespan = max(max(row) for row in (f_end + b_end + cf_end + cb_end))
 
     comm_exposed = 0.0
@@ -310,8 +320,8 @@ def _simulate_contended(t_f, t_b, c_links, B, warmup_counts, cb, in_f, in_b,
     for (kind, j, i), d in dur.items():
         if kind in ("F", "B"):
             stage_compute[i] += d
-    comm_total = sum(d for (k, _, _), d in dur.items()
-                     if k in ("CF", "CB", "SYNC"))
+    comm_total = plain_sum(d for (k, _, _), d in dur.items()
+                           if k in ("CF", "CB", "SYNC"))
     comm_exposed = 0.0
     for v, ps in deps_of.items():
         if v[0] not in ("F", "B") or not ps:
@@ -431,12 +441,8 @@ def _simulate_graph(t_f, t_b, c_links, B, warmup_counts, cb, in_f, in_b, *,
     stage_idle = [makespan - stage_compute[i] - stage_comm_blocking[i]
                   for i in range(S)]
 
-    # a plain left-to-right float sum, as the fast path accumulates it
-    # (Python 3.12's sum() compensates, which can differ in the last bit)
-    comm_total = 0.0
-    for (k, _, _), d in dur.items():
-        if k in ("CF", "CB"):
-            comm_total += d
+    comm_total = plain_sum(d for (k, _, _), d in dur.items()
+                           if k in ("CF", "CB"))
     # exposed comm: compute ops delayed specifically by their comm dependency
     comm_exposed = 0.0
     for v, ps in deps.items():

@@ -39,9 +39,13 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
     """Build ``loss(staged, shared, consts, batch) -> (loss, metrics)``.
 
     ``spec`` is a family staging (see ``parallel/staging.py``) providing
-    make_io / stage_fn / head_loss / zero_carry."""
+    make_io / stage_fn / head_loss / zero_carry.  Only those callables are
+    kept, never ``spec`` itself: it holds the unplaced parameter arrays,
+    which the caller frees once it has placed them."""
     S = spec.n_stages
     n_mb = n_microbatches
+    make_io, stage_fn = spec.make_io, spec.stage_fn
+    head_loss, zero_carry = spec.head_loss, spec.zero_carry
 
     def inner(staged_local, consts_local, shared, io):
         staged1 = jax.tree.map(lambda x: x[0], staged_local)
@@ -50,15 +54,15 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
         is_last = (sidx == S - 1).astype(jnp.float32)
         carry0 = jax.tree.map(
             lambda x: jax.lax.pcast(x, (stage_axis,), to="varying"),
-            spec.zero_carry(io))
+            zero_carry(io))
         perm = [(i, i + 1) for i in range(S - 1)]
 
         def step(carry, t):
             io_t = jax.tree.map(lambda a: a[jnp.clip(t, 0, n_mb - 1)], io)
-            carry = spec.stage_fn(staged1, consts1, shared, carry, io_t)
+            carry = stage_fn(staged1, consts1, shared, carry, io_t)
             out_idx = jnp.clip(t - (S - 1), 0, n_mb - 1)
             io_out = jax.tree.map(lambda a: a[out_idx], io)
-            ce_sum, ntok, aux = spec.head_loss(shared, carry, io_out)
+            ce_sum, ntok, aux = head_loss(shared, carry, io_out)
             valid = jnp.asarray(t >= S - 1, jnp.float32) * is_last
             if S > 1:
                 carry = jax.tree.map(
@@ -79,7 +83,7 @@ def pipeline_loss_fn(spec, mesh, n_microbatches: int, stage_axis: str = "pod"):
         axis_names={stage_axis})
 
     def loss_fn(staged, shared, consts, batch):
-        io = spec.make_io(shared, batch, n_mb)
+        io = make_io(shared, batch, n_mb)
         ce_v, tok_v, aux_v = smapped(staged, consts, shared, io)
         tokens = jnp.maximum(jnp.sum(tok_v), 1.0)
         ce = jnp.sum(ce_v) / tokens

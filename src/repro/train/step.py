@@ -121,7 +121,9 @@ def make_pipeline_train_step(cfg: ArchConfig, opt_cfg: OptimizerConfig, *,
     """Returns (train_step, staging, opt_init, shardings dict).
 
     ``abstract=True`` builds the staging from ShapeDtypeStructs (dry-run —
-    no allocation)."""
+    no allocation).  The step does not keep the staging: once its arrays
+    are placed, the caller drops it (and ``params``) to free the unplaced
+    copies."""
     model = build_model(cfg, param_dtype=param_dtype)
     if params is None:
         if abstract:

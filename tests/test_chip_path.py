@@ -1,13 +1,21 @@
 """CPU checks of what the chip run relies on: ``api.fit`` donates its
-training state, and the compile cache lands where it is told to."""
+training state, the pipeline step lets its unplaced parameters go, and the
+compile cache lands where it is told to."""
+import gc
 import math
+import weakref
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro import api, compile_cache
 from repro.api import facade
 from repro.configs import get_config
+from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.mesh import make_mesh
+from repro.train.optimizer import OptimizerConfig
+from repro.train.step import make_pipeline_train_step
 from repro.train.trainer import TrainerConfig
 
 
@@ -37,6 +45,31 @@ def test_fit_donates_params_and_opt_state(monkeypatch, tmp_path):
     assert all(math.isfinite(h["loss"]) for h in res["history"])
     assert not any(x.is_deleted() for x in jax.tree.leaves(res["state"]))
     assert any(tmp_path.iterdir())
+
+
+def test_pipeline_step_frees_the_unplaced_staging():
+    cfg = get_config("gpt-2b").reduced()
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
+    step, staging, opt_init, sh = make_pipeline_train_step(
+        cfg, OptimizerConfig(warmup_steps=1, total_steps=2), mesh=mesh,
+        n_stages=1, n_microbatches=2, act_dtype=jnp.float32)
+    staged = jax.device_put(staging.staged, sh["staged"])
+    shared = jax.device_put(staging.shared, sh["shared"])
+    consts = jax.device_put(staging.consts, sh["consts"])
+    gone = [weakref.ref(staging)] + [
+        weakref.ref(x) for x in jax.tree.leaves(
+            (staging.staged, staging.shared, staging.consts))]
+    del staging
+    gc.collect()
+    # the step kept neither the staging nor any of its unplaced arrays ...
+    assert [r for r in gone if r() is not None] == []
+    # ... and still trains from the placed state
+    data = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=4)
+    opt_state = opt_init({"staged": staged, "shared": shared})
+    with jax.set_mesh(mesh):
+        *_, metrics = jax.jit(step)(staged, shared, consts, opt_state,
+                                    make_batch(data, 0))
+    assert math.isfinite(float(metrics["loss"]))
 
 
 @pytest.mark.parametrize("env,expected", [

@@ -15,7 +15,7 @@ import pytest
 
 from repro import api
 from repro.core import paper_case_study_cluster
-from repro.core.pipesim import ascii_timeline, sim_memo_stats
+from repro.core.pipesim import ascii_timeline, plain_sum, sim_memo_stats
 from repro.core.planner import PlannerConfig
 from repro.migrate import (
     diff_layouts, layout_from_strategy, lost_devices, price_migration,
@@ -61,24 +61,15 @@ def chaos_run(tmp_path_factory):
 # ---------------------------------------------------------------------------
 
 
-def _plain_sum(xs):
-    """Left-to-right float accumulation, as the engines accumulate (the
-    built-in ``sum`` compensates since Python 3.12)."""
-    acc = 0.0
-    for x in xs:
-        acc += x
-    return acc
-
-
 def test_sim_adapter_span_sums_reproduce_engine_totals(exe_case):
     res = exe_case.simulate(priced=False)
     tr = trace_from_sim(res)
     compute = [s for s in tr.spans if s.cat == "compute"]
     for i, expected in enumerate(res.stage_compute):
-        got = _plain_sum(s.dur for s in compute if s.args["stage"] == i)
+        got = plain_sum(s.dur for s in compute if s.args["stage"] == i)
         assert got == expected          # exact float equality, not approx
-    comm = _plain_sum(s.dur for s in tr.spans
-                      if s.cat == "comm" and s.args.get("kind") in ("CF", "CB"))
+    comm = plain_sum(s.dur for s in tr.spans
+                     if s.cat == "comm" and s.args.get("kind") in ("CF", "CB"))
     assert comm == res.comm_total
     assert tr.meta["comm_exposed_s"] == res.comm_exposed
     assert tr.makespan() == res.makespan

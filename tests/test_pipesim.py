@@ -79,7 +79,7 @@ def _assert_same(a, b):
                                    "eager"])
 @pytest.mark.parametrize("seed", range(4))
 def test_fast_path_matches_graph_all_schedules(sched, seed):
-    rng = random.Random(hash((sched, seed)))
+    rng = random.Random(f"{sched}:{seed}")    # str seeds are not salted
     S = rng.randint(1, 6)
     B = rng.randint(1, 16)
     t_f = [rng.uniform(0.1, 2.0) for _ in range(S)]
