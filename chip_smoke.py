@@ -91,19 +91,12 @@ class Checks:
             self.failed.append(name)
 
 
-class CompileClock:
-    """Seconds JAX spent in backend compiles, from its monitoring events."""
-
-    def __init__(self):
-        import jax
-        from jax._src import dispatch
-        self.total = 0.0
-        self._event = dispatch.BACKEND_COMPILE_EVENT
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == self._event:
-            self.total += duration
+def compile_s() -> float:
+    """Seconds JAX has spent building executables so far, from the
+    ``jax.backend_compile_s`` histogram that ``watch_compiles`` feeds."""
+    from repro.obs.metrics import DEFAULT_REGISTRY
+    hist = DEFAULT_REGISTRY.snapshot()["histograms"]
+    return hist.get("jax.backend_compile_s", {"sum": 0.0})["sum"]
 
 
 def _gib(n: float) -> str:
@@ -116,8 +109,7 @@ def _max_rel_err(a, b) -> float:
     return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
 
-def train_phase(checks: Checks, clock: CompileClock, cfg, *, batch: int,
-                steps: int) -> None:
+def train_phase(checks: Checks, cfg, *, batch: int, steps: int) -> None:
     """``api.fit`` for ``steps`` steps from a fresh state; checks the loss
     curve against the same model's reference loss."""
     import jax
@@ -145,7 +137,7 @@ def train_phase(checks: Checks, clock: CompileClock, cfg, *, batch: int,
         leaf.delete()
     del params
 
-    c0 = clock.total
+    c0 = compile_s()
     with tempfile.TemporaryDirectory() as ckpt_dir:
         harp = api.HarpConfig(seq_len=seq, global_batch=batch,
                               trainer=TrainerConfig(
@@ -155,7 +147,7 @@ def train_phase(checks: Checks, clock: CompileClock, cfg, *, batch: int,
                       log_fn=log)
     hist = res["history"]
     times = [h["time_s"] for h in hist]
-    log(f"[train] compile s: {clock.total - c0:.3f}")
+    log(f"[train] compile s: {compile_s() - c0:.3f}")
     log(f"[train] step s: first (with compile) {times[0]:.4f}; then "
         + ", ".join(f"{t:.4f}" for t in times[1:])
         + (f"; median {statistics.median(times[1:]):.4f}" if len(times) > 1
@@ -182,8 +174,7 @@ def train_phase(checks: Checks, clock: CompileClock, cfg, *, batch: int,
            f"first {losses[0]!r}, last {losses[-1]!r}")
 
 
-def flash_phase(checks: Checks, clock: CompileClock, cfg, *,
-                batch: int) -> None:
+def flash_phase(checks: Checks, cfg, *, batch: int) -> None:
     """Flash attention forward + backward through ``ops.flash_attention``
     vs ``attention_ref`` at highest precision, at ``cfg``'s head widths."""
     import jax
@@ -209,9 +200,9 @@ def flash_phase(checks: Checks, clock: CompileClock, cfg, *,
     lowered = kernel.lower(q, k, v).as_text()
     checks("flash.mosaic", "tpu_custom_call" in lowered,
            "the jitted program calls the compiled Mosaic kernel")
-    c0 = clock.total
+    c0 = compile_s()
     got = jax.block_until_ready(kernel(q, k, v))
-    log(f"[flash] compile s: {clock.total - c0:.3f}")
+    log(f"[flash] compile s: {compile_s() - c0:.3f}")
     t0 = time.perf_counter()
     jax.block_until_ready(kernel(q, k, v))
     log(f"[flash] fwd+bwd s (second call): {time.perf_counter() - t0:.6f}")
@@ -224,7 +215,7 @@ def flash_phase(checks: Checks, clock: CompileClock, cfg, *,
                f"limit {FLASH_REL_TOL}")
 
 
-def pipeline_phase(checks: Checks, clock: CompileClock, cfg, devices, *,
+def pipeline_phase(checks: Checks, cfg, devices, *,
                    batch: int, seq: int, n_microbatches: int,
                    steps: int) -> None:
     """The 2-stage pipeline train step over ``devices`` vs the single-program
@@ -283,7 +274,7 @@ def pipeline_phase(checks: Checks, clock: CompileClock, cfg, devices, *,
                for i in range(steps)]
 
     with jax.set_mesh(mesh):
-        c0 = clock.total
+        c0 = compile_s()
         # the state leaves the step placed as it entered, so the next step
         # takes it as is and the donated buffers can be reused
         compiled = jax.jit(
@@ -293,7 +284,7 @@ def pipeline_phase(checks: Checks, clock: CompileClock, cfg, devices, *,
                            NamedSharding(mesh, P())),
             donate_argnums=(0, 1, 3)).lower(
                 staged, shared, consts, opt_state, batches[0]).compile()
-        log(f"[pipeline] compile s: {clock.total - c0:.3f}")
+        log(f"[pipeline] compile s: {compile_s() - c0:.3f}")
         hlo = compiled.as_text()
         log("[pipeline] collectives in the step: " + ", ".join(
             f"{op} x{hlo.count(op + '(') + hlo.count(op + '-start(')}"
@@ -368,6 +359,7 @@ def main(argv=None) -> int:
 
     from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
+    from repro.obs.metrics import watch_compiles
 
     cache_dir = enable_compile_cache()
     devices = jax.devices()
@@ -383,18 +375,19 @@ def main(argv=None) -> int:
     log(f"device: {dev.device_kind} ({dev.platform}), count {len(devices)}; "
         f"jax {jax.__version__}; compile cache {cache_dir}")
 
-    checks, clock = Checks(), CompileClock()
+    watch_compiles()
+    checks = Checks()
     full = get_config(ARCH)
     if args.chips == 4:
-        pipeline_phase(checks, clock,
+        pipeline_phase(checks,
                        dataclasses.replace(full, n_layers=PIPE_LAYERS),
                        devices[:4], batch=TRAIN_BATCH, seq=full.max_position,
                        n_microbatches=PIPE_MICROBATCHES, steps=TRAIN_STEPS)
     else:
-        train_phase(checks, clock,
+        train_phase(checks,
                     dataclasses.replace(full, n_layers=TRAIN_LAYERS),
                     batch=TRAIN_BATCH, steps=TRAIN_STEPS)
-        flash_phase(checks, clock, full, batch=FLASH_BATCH)
+        flash_phase(checks, full, batch=FLASH_BATCH)
     if checks.failed:
         print(f"chip_smoke.py: failed checks: {checks.failed}",
               file=sys.stderr)

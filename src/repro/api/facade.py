@@ -30,6 +30,7 @@ from repro.core.pipesim import SimResult, simulate
 from repro.core.planner import HAPTPlanner
 from repro.core.strategy import IntraOpPlan, ParallelStrategy
 from repro.data.pipeline import DataConfig
+from repro.obs.metrics import watch_compiles
 from repro.parallel.sharding import batch_shard_sizes, intra_op_mesh_axes
 from repro.runtime.controller import (
     ControllerConfig, ElasticController, ReplanDecision,
@@ -794,9 +795,11 @@ def fit(arch: Union[str, ArchConfig],
     Pass ``train_step`` + ``state`` to run a custom step function (toy
     models, synthetic clocks); otherwise the arch's model and an AdamW
     optimizer are built.  ``config.data`` (or a ``DataConfig`` derived from
-    the arch) feeds the deterministic synthetic pipeline."""
+    the arch) feeds the deterministic synthetic pipeline.  JAX's compiles
+    are counted on the default metrics registry (``watch_compiles``)."""
     import jax
 
+    watch_compiles()
     cfg = config if config is not None else HarpConfig()
     arch_cfg = _resolve_arch(arch)
     if train_step is None:

@@ -27,8 +27,16 @@ def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
     JAX reads ``$JAX_COMPILATION_CACHE_DIR`` itself, so where it is set no
-    other directory is configured here."""
+    other directory is configured here.
+
+    The cache key takes in each op's name (its ``jax.named_scope`` path), so
+    an executable loaded from the cache carries the names of the program
+    that asked for it, not those of an older program that lowered to the
+    same computation.  Ops' locations keep no Python frames, so the key holds
+    no source path or line and a checkout that moves still hits."""
     path = compile_cache_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return path

@@ -108,6 +108,7 @@ def _attention(q, k, v, *, causal, window, use_pallas):
     return attention_ref(q, k, v, causal=causal, window=window)
 
 
+@jax.named_scope("attention")
 def self_attention(p: Dict[str, Any], h: jnp.ndarray, *,
                    n_heads: int, n_kv_heads: int, head_dim: int,
                    rope_theta: float, causal: bool = True, window: int = 0,

@@ -139,6 +139,7 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("head")
 def softmax_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
                           z_loss: float = 0.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Token-level CE with f32 accumulation but NO materialized f32 copy of

@@ -21,6 +21,7 @@ def mlp_init(rng, d_model: int, d_ff: int, activation: str,
     return p
 
 
+@jax.named_scope("mlp")
 def mlp(p: Dict[str, Any], h: jnp.ndarray, activation: str) -> jnp.ndarray:
     up = linear(h, p["w_up"])
     if activation in GATED:

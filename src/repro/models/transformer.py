@@ -105,6 +105,7 @@ def _scan_blocks(cfg: ArchConfig, blocks: Params, h: jnp.ndarray, *,
     return h
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
     from repro.models.common import act_dtype_cast
     h = act_dtype_cast(params["embed"][tokens])
@@ -113,6 +114,7 @@ def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray) -> jnp.nd
     return shard_act(h, ("batch", "seq", "embed"))
 
 
+@jax.named_scope("head")
 def lm_head(cfg: ArchConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -25,8 +25,7 @@ from typing import Any, Dict, Optional
 from repro.obs.drift import DriftLedger, DriftReport
 from repro.obs.metrics import (DEFAULT_REGISTRY, MetricsRegistry,
                                default_registry, record_decision,
-                               record_serve_result, sync_from_injector,
-                               sync_from_sim_memo)
+                               sync_from_sim_memo, watch_compiles)
 from repro.obs.sink import SINK_SCHEMA, RunLog, iter_kind, read_runlog
 from repro.obs.trace import (OBS_TRACE_SCHEMA, Counter, Span, Trace,
                              render_ascii, trace_from_decisions,
@@ -67,8 +66,7 @@ __all__ = [
     "render_ascii", "trace_from_sim", "trace_from_netsim",
     "trace_from_migration", "trace_from_serve", "trace_from_decisions",
     "MetricsRegistry", "DEFAULT_REGISTRY", "default_registry",
-    "sync_from_sim_memo", "sync_from_injector", "record_decision",
-    "record_serve_result",
+    "sync_from_sim_memo", "record_decision", "watch_compiles",
     "DriftLedger", "DriftReport",
     "SINK_SCHEMA", "RunLog", "read_runlog", "iter_kind",
 ]

@@ -3,18 +3,17 @@ labels, deterministic snapshots, and shims over the stack's pre-existing
 scattered counters.
 
 The registry is intentionally tiny and dependency-free (the planner stays
-numpy-only; nothing here imports jax).  Series are keyed
-``(name, sorted(label items))`` and snapshots render as
+numpy-only; only :func:`watch_compiles` imports jax, when called).  Series
+are keyed ``(name, sorted(label items))`` and snapshots render as
 ``name{k=v,...}`` in sorted order — two runs that record the same values
 produce byte-identical snapshot dicts.
 
 Back-compat shims (the old surfaces keep working; ``obs.metrics`` *reads*
 them): :func:`sync_from_sim_memo` mirrors ``pipesim.sim_memo_stats()``
-into ``sim_memo.*`` gauges, :func:`sync_from_injector` mirrors a chaos
-``FaultInjector.stats()`` into ``chaos.*``, and
-:func:`record_decision` folds one ``ReplanDecision`` into
-``controller.*`` counters.  ``checkpoint/ckpt.py`` increments
-``ckpt.bytes_written`` on the default registry at every save.
+into ``sim_memo.*`` gauges, and :func:`record_decision` folds one
+``ReplanDecision`` into ``controller.*`` counters.
+``checkpoint/ckpt.py`` increments ``ckpt.bytes_written`` on the default
+registry at every save, and :func:`watch_compiles` counts JAX's compiles.
 """
 from __future__ import annotations
 
@@ -109,16 +108,6 @@ def sync_from_sim_memo(reg: Optional[MetricsRegistry] = None
     return reg
 
 
-def sync_from_injector(injector, reg: Optional[MetricsRegistry] = None
-                       ) -> MetricsRegistry:
-    """Mirror a chaos ``FaultInjector.stats()`` dict into ``chaos.<seam>``
-    gauges."""
-    reg = reg if reg is not None else DEFAULT_REGISTRY
-    for seam, n in sorted(injector.stats().items()):
-        reg.gauge("chaos.draws", n, seam=seam)
-    return reg
-
-
 def record_decision(d, reg: Optional[MetricsRegistry] = None
                     ) -> MetricsRegistry:
     """Fold one ``ReplanDecision`` into ``controller.*``: per-action
@@ -137,17 +126,49 @@ def record_decision(d, reg: Optional[MetricsRegistry] = None
     return reg
 
 
-def record_serve_result(res, reg: Optional[MetricsRegistry] = None
-                        ) -> MetricsRegistry:
-    """Fold a ``ServeSimResult`` into ``serve.*`` (kv_violations — always 0
-    by construction — rejections, handoffs, per-pool busy seconds)."""
+# ---------------------------------------------------------------------------
+# Compiles, from JAX's monitoring events
+# ---------------------------------------------------------------------------
+
+# JAX reports this duration for every executable it builds, whether XLA
+# compiled it or the persistent cache held it; a cache hit is reported
+# besides, so ``jax.cache_loads`` is a part of ``jax.backend_compiles``.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_WATCHED = []      # registries whose listeners are registered
+
+
+def watch_compiles(reg: Optional[MetricsRegistry] = None
+                   ) -> MetricsRegistry:
+    """Count JAX's compiles into ``reg`` from now on: ``jax.backend_compiles``
+    (each executable built, compiled or loaded from the persistent cache),
+    the ``jax.backend_compile_s`` histogram of their seconds, and
+    ``jax.cache_loads`` (those the persistent cache held).
+
+    Each increment also leaves a mark of the counter's name on the
+    ``jax.profiler`` host timeline, so a trace shows when each compile
+    ended.  Listeners are registered once per process and registry: a
+    second call changes nothing."""
     reg = reg if reg is not None else DEFAULT_REGISTRY
-    reg.inc("serve.kv_violations", res.kv_violations)
-    reg.inc("serve.rejected", res.n_rejected)
-    reg.inc("serve.completed", res.n_completed)
-    reg.inc("serve.handoffs", res.n_handoffs)
-    reg.inc("serve.handoff_bytes", res.handoff_bytes)
-    for pool, busy in sorted(res.pool_busy_s.items()):
-        reg.gauge("serve.busy_s", busy["prefill"], pool=pool, kind="prefill")
-        reg.gauge("serve.busy_s", busy["decode"], pool=pool, kind="decode")
+    if any(r is reg for r in _WATCHED):
+        return reg
+    import jax
+
+    def count(name: str) -> None:
+        reg.inc(name)
+        with jax.profiler.TraceAnnotation(name):
+            pass
+
+    def on_duration(event: str, seconds: float, **_) -> None:
+        if event == _COMPILE_EVENT:
+            reg.observe("jax.backend_compile_s", seconds)
+            count("jax.backend_compiles")
+
+    def on_event(event: str, **_) -> None:
+        if event == _CACHE_HIT_EVENT:
+            count("jax.cache_loads")
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    _WATCHED.append(reg)
     return reg

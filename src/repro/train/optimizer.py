@@ -62,6 +62,7 @@ def make_adamw(cfg: OptimizerConfig):
                         jax.tree.map(zeros, params),
                         jax.tree.map(zeros, params), master)
 
+    @jax.named_scope("optimizer")
     def update(grads, state: OptState, params):
         step = state.step + 1
         gn = global_norm(grads)

@@ -19,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models import build_model
 from repro.models.common import activation_sharding
+from repro.obs.metrics import watch_compiles
 from repro.parallel import sharding as shd
 from repro.parallel.pipeline import pipeline_loss_fn
 from repro.parallel.staging import build_staging
@@ -123,7 +124,9 @@ def make_pipeline_train_step(cfg: ArchConfig, opt_cfg: OptimizerConfig, *,
     ``abstract=True`` builds the staging from ShapeDtypeStructs (dry-run —
     no allocation).  The step does not keep the staging: once its arrays
     are placed, the caller drops it (and ``params``) to free the unplaced
-    copies."""
+    copies.  JAX's compiles are counted from here on
+    (``watch_compiles``): no ``api.fit`` reaches this step yet."""
+    watch_compiles()
     model = build_model(cfg, param_dtype=param_dtype)
     if params is None:
         if abstract:

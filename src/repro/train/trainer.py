@@ -91,13 +91,14 @@ class Trainer:
         return step
 
     def checkpoint(self, step: int):
-        host_state = jax.tree.map(np.asarray, self.state)
-        extra = {"data_seed": self.data_cfg.seed}
-        if self._ckptr is not None:
-            self._ckptr.save(step, host_state, extra=extra)
-        else:
-            ckpt_lib.save(self.cfg.ckpt_dir, step, host_state,
-                          extra=extra, keep=self.cfg.keep_ckpts)
+        with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+            host_state = jax.tree.map(np.asarray, self.state)
+            extra = {"data_seed": self.data_cfg.seed}
+            if self._ckptr is not None:
+                self._ckptr.save(step, host_state, extra=extra)
+            else:
+                ckpt_lib.save(self.cfg.ckpt_dir, step, host_state,
+                              extra=extra, keep=self.cfg.keep_ckpts)
 
     def run(self, start_step: Optional[int] = None) -> Dict[str, Any]:
         self._install_sigterm()
@@ -105,39 +106,45 @@ class Trainer:
         history = []
         keys = self._keys
         while step < self.cfg.total_steps and not self._stop:
-            batch = make_batch(self.data_cfg, step)
-            t0 = self.clock()
-            out = self.train_step(*[self.state[k] for k in keys], batch)
-            *new_vals, metrics = out
-            jax.block_until_ready(new_vals[0])
-            dt = self.clock() - t0
-            self.state = dict(zip(keys, new_vals))
-            step += 1
+            with jax.profiler.StepTraceAnnotation("trainer.step",
+                                                  step_num=step):
+                with jax.profiler.TraceAnnotation("trainer.batch"):
+                    batch = make_batch(self.data_cfg, step)
+                t0 = self.clock()
+                with jax.profiler.TraceAnnotation("trainer.dispatch"):
+                    out = self.train_step(*[self.state[k] for k in keys],
+                                          batch)
+                *new_vals, metrics = out
+                with jax.profiler.TraceAnnotation("trainer.sync"):
+                    jax.block_until_ready(new_vals[0])
+                dt = self.clock() - t0
+                self.state = dict(zip(keys, new_vals))
+                step += 1
 
-            if self.on_step_time is not None:
-                self.on_step_time(step, dt)
+                if self.on_step_time is not None:
+                    self.on_step_time(step, dt)
 
-            # straggler watch (EWMA seeded from the 2nd step — the 1st pays
-            # jit compilation and would mask every later straggler)
-            if self._ewma is None:
-                self._ewma = dt
-            elif step == 2:
-                self._ewma = dt
-            else:
-                if dt > self.cfg.replan_threshold * self._ewma \
-                        and self.on_straggler is not None:
-                    self.on_straggler(step, dt, self._ewma)
-                a = self.cfg.ewma_alpha
-                self._ewma = (1 - a) * self._ewma + a * dt
+                # straggler watch (EWMA seeded from the 2nd step — the 1st
+                # pays jit compilation and would mask every later straggler)
+                if self._ewma is None:
+                    self._ewma = dt
+                elif step == 2:
+                    self._ewma = dt
+                else:
+                    if dt > self.cfg.replan_threshold * self._ewma \
+                            and self.on_straggler is not None:
+                        self.on_straggler(step, dt, self._ewma)
+                    a = self.cfg.ewma_alpha
+                    self._ewma = (1 - a) * self._ewma + a * dt
 
-            if step % self.cfg.log_every == 0 or step == 1:
-                m = {k: float(v) for k, v in metrics.items()}
-                history.append({"step": step, "time_s": dt, **m})
-                self.log(f"[step {step:5d}] "
-                         + " ".join(f"{k}={v:.4f}" for k, v in m.items())
-                         + f" ({dt*1e3:.0f} ms)")
-            if step % self.cfg.ckpt_every == 0:
-                self.checkpoint(step)
+                if step % self.cfg.log_every == 0 or step == 1:
+                    m = {k: float(v) for k, v in metrics.items()}
+                    history.append({"step": step, "time_s": dt, **m})
+                    self.log(f"[step {step:5d}] "
+                             + " ".join(f"{k}={v:.4f}" for k, v in m.items())
+                             + f" ({dt*1e3:.0f} ms)")
+                if step % self.cfg.ckpt_every == 0:
+                    self.checkpoint(step)
         if self._stop:
             self.log("[trainer] SIGTERM — checkpointing and exiting")
             self.checkpoint(step)

@@ -96,15 +96,22 @@ def test_enable_compile_cache(monkeypatch, env_dir):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     else:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
-    prev = jax.config.jax_compilation_cache_dir
+    keys = ("jax_compilation_cache_dir",
+            "jax_compilation_cache_include_metadata_in_key",
+            "jax_traceback_in_locations_limit")
+    prev = {k: getattr(jax.config, k) for k in keys}
     try:
         path = compile_cache.enable_compile_cache()
         if env_dir is None:
             assert path == str(compile_cache.DEFAULT_DIR)
             assert jax.config.jax_compilation_cache_dir == path
         else:
-            # JAX reads the variable itself; nothing else is configured
+            # JAX reads the variable itself; no other directory is set
             assert path == env_dir
-            assert jax.config.jax_compilation_cache_dir == prev
+            assert jax.config.jax_compilation_cache_dir == prev[keys[0]]
+        # op names are in the key; source paths and lines are not
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert jax.config.jax_traceback_in_locations_limit == 0
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        for k, v in prev.items():
+            jax.config.update(k, v)
